@@ -1,0 +1,9 @@
+"""Share of the traced window, in %, in which no operation ran on the
+chip (averaged over the cell's chips), in an LLM training cell."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr or tr["window_s"] <= 0 or tr["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
