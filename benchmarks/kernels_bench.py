@@ -172,25 +172,6 @@ def run(quick: bool = True):
                  "hbm_bytes_kernel": kern_hbm,
                  "traffic_ratio": round(naive_hbm / kern_hbm, 2)})
     # ------------------------------------------------------------------
-    # telemetry kernel-timing hook overhead (repro.telemetry.ktime):
-    # the same single-segment launch dispatched plain (oracle) vs under
-    # ``kernel_timing`` (kernel) — so the gated kernel/oracle ratio IS
-    # the hook's overhead (perf.counter + block_until_ready + one
-    # histogram append per dispatch), held under the standard 20% gate.
-    from repro.telemetry import MetricsRegistry, kernel_timing
-    seg1 = jnp.zeros((n_dev,), jnp.int32)
-    us = _time(lambda m_, w_: ops.segment_agg(m_, w_, seg1, 1), mat, ws)
-    treg = MetricsRegistry()
-    with kernel_timing(treg):
-        us_t = _time(lambda m_, w_: ops.segment_agg(m_, w_, seg1, 1),
-                     mat, ws)
-    rows.append({"setting": "segment_agg_timed_64x500k",
-                 "oracle_us_per_call": round(us, 1),
-                 "kernel_us_per_call": round(us_t, 1),
-                 "timed_dispatches": int(
-                     treg.counters.get("kernel/segment_agg_calls", 0)),
-                 "overhead_ratio": round(us_t / max(us, 1e-9), 3)})
-    # ------------------------------------------------------------------
     # end-to-end aggregation: per-leaf tree-path oracle vs flat-bank
     # engine (flatten -> segment_agg -> unflatten) on a nested pytree
     leaf = p2 // 4

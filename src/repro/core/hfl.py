@@ -436,10 +436,11 @@ def make_local_trainer(loss_fn: Callable, lr: float, batch_size: int):
 
         def step(p, bidx):
             g = jax.grad(loss_fn)(p, {"x": x[bidx], "y": y[bidx]})
-            return jax.tree.map(
-                lambda a, b: (a.astype(jnp.float32)
-                              - lr * b.astype(jnp.float32)).astype(a.dtype),
-                p, g), None
+            with jax.named_scope("hfl.update"):
+                return jax.tree.map(
+                    lambda a, b: (a.astype(jnp.float32)
+                                  - lr * b.astype(jnp.float32)
+                                  ).astype(a.dtype), p, g), None
 
         params, _ = jax.lax.scan(step, params, idx)
         return params
@@ -447,6 +448,7 @@ def make_local_trainer(loss_fn: Callable, lr: float, batch_size: int):
     def local_train(bank, x, y, gamma1_dev, max_g1: int, key):
         n, n_local = x.shape[0], x.shape[1]
 
+        @jax.named_scope("hfl.local")
         def one_epoch(carry, e):
             bank, key = carry
             key, sub = jax.random.split(key)
@@ -566,22 +568,27 @@ def make_cloud_round(loss_fn: Callable, lr: float, batch_size: int,
             active_dev = t2 < g2_dev
             g1_eff = jnp.where(active_dev, g1_dev, 0)
             bank = local_train(bank, x, y, g1_eff, max_g1, sub)
-            a = agg(spec.flatten(bank))
-            active_edge = (t2 < g2).reshape(-1, 1)
-            edge_mat = jnp.where(active_edge, a, edge_mat)
-            # devices resume from their edge's current model (each shard
-            # gathers only its own rows under the mesh path)
-            bank = spec.unflatten(resync(edge_mat))
+            with jax.named_scope("hfl.edge_agg"):
+                a = agg(spec.flatten(bank))
+                active_edge = (t2 < g2).reshape(-1, 1)
+                edge_mat = jnp.where(active_edge, a, edge_mat)
+                # devices resume from their edge's current model (each
+                # shard gathers only its own rows under the mesh path)
+                bank = spec.unflatten(resync(edge_mat))
             return (bank, edge_mat, key), None
 
-        edge_mat0 = agg(spec.flatten(bank))
+        with jax.named_scope("hfl.edge_agg"):
+            edge_mat0 = agg(spec.flatten(bank))
         (bank, edge_mat, _), _ = jax.lax.scan(
             t2_step, (bank, edge_mat0, key), jnp.arange(max_g2))
-        edge_sizes = jax.ops.segment_sum(sizes, edge_assign, n_edges)
-        glob = agg1(edge_mat, edge_sizes)[0]
-        global_model = spec.unflatten_model(glob)
-        bank = broadcast_model(global_model, x.shape[0])
-        return bank, global_model, spec.unflatten(edge_mat)
+        with jax.named_scope("hfl.cloud_agg"):
+            edge_sizes = jax.ops.segment_sum(sizes, edge_assign, n_edges)
+            glob = agg1(edge_mat, edge_sizes)[0]
+            global_model = spec.unflatten_model(glob)
+            bank = broadcast_model(global_model, x.shape[0])
+        with jax.named_scope("hfl.edge_agg"):
+            edge_models = spec.unflatten(edge_mat)
+        return bank, global_model, edge_models
 
     return _jit_round(cloud_round, mesh, n_row_args=5,
                       donate=ctx.donate_argnums(0))
@@ -669,17 +676,19 @@ def make_edge_round(loss_fn: Callable, lr: float, batch_size: int,
             active_dev = t2 < g2_dev
             g1_eff = jnp.where(active_dev, g1_dev, 0)
             bank = local_train(bank, x, y, g1_eff, max_g1, sub)
-            a = agg(spec.flatten(bank))
-            active_edge = jnp.logical_and(t2 < g2, edge_1h)
-            edge_mat = jnp.where(active_edge, a, edge_mat)
-            # resync only this edge's rows; the rest of the bank is
-            # other edges' in-flight state and must not move
-            mat = jnp.where(row_mask, resync(edge_mat),
-                            spec.flatten(bank))
-            bank = spec.unflatten(mat)
+            with jax.named_scope("hfl.edge_agg"):
+                a = agg(spec.flatten(bank))
+                active_edge = jnp.logical_and(t2 < g2, edge_1h)
+                edge_mat = jnp.where(active_edge, a, edge_mat)
+                # resync only this edge's rows; the rest of the bank is
+                # other edges' in-flight state and must not move
+                mat = jnp.where(row_mask, resync(edge_mat),
+                                spec.flatten(bank))
+                bank = spec.unflatten(mat)
             return (bank, edge_mat, key), None
 
-        edge_mat0 = agg(spec.flatten(bank))
+        with jax.named_scope("hfl.edge_agg"):
+            edge_mat0 = agg(spec.flatten(bank))
         (bank, edge_mat, _), _ = jax.lax.scan(
             t2_step, (bank, edge_mat0, key), jnp.arange(max_g2))
         edge_vec = jnp.take(edge_mat, edge_id, axis=0)
@@ -711,15 +720,16 @@ def make_fedavg_round(loss_fn: Callable, lr: float, batch_size: int,
         spec = flatbank.bank_spec(bank)
         g1_dev = jnp.where(participate, g1, 0)
         bank = local_train(bank, x, y, g1_dev, max_g1, key)
-        w = sizes * participate.astype(sizes.dtype)
-        seg = jnp.zeros((n,), jnp.int32)
-        if mesh is None:
-            glob = ops.segment_agg(spec.flatten(bank), w, seg, 1)[0]
-        else:
-            glob = _smap_segment_agg(mesh, 1)(spec.flatten(bank), w,
-                                              seg)[0]
-        global_model = spec.unflatten_model(glob)
-        bank = broadcast_model(global_model, n)
+        with jax.named_scope("hfl.cloud_agg"):
+            w = sizes * participate.astype(sizes.dtype)
+            seg = jnp.zeros((n,), jnp.int32)
+            if mesh is None:
+                glob = ops.segment_agg(spec.flatten(bank), w, seg, 1)[0]
+            else:
+                glob = _smap_segment_agg(mesh, 1)(spec.flatten(bank), w,
+                                                  seg)[0]
+            global_model = spec.unflatten_model(glob)
+            bank = broadcast_model(global_model, n)
         return bank, global_model
 
     return _jit_round(round_, mesh, n_row_args=5,

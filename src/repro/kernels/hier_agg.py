@@ -119,6 +119,7 @@ def _segment_agg_call(bank, w32, inv, segment_ids, num_segments: int,
         out_specs=pl.BlockSpec((e, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((e, p_pad), jnp.float32),
         interpret=interpret,
+        name="segment_agg",
     )(segment_ids.reshape(1, n).astype(jnp.int32),
       w32.reshape(1, n), inv, bank)
     return out[:, :p]
@@ -223,6 +224,7 @@ def segment_broadcast(models, segment_ids, *, out_dtype=None,
         out_specs=pl.BlockSpec((n, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, p_pad), out_dtype),
         interpret=interpret,
+        name="segment_broadcast",
     )(segment_ids.reshape(n, 1).astype(jnp.int32), models)
     return out[:, :p]
 

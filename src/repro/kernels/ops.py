@@ -20,7 +20,6 @@ import jax
 from repro.kernels import flash_attention as _fa
 from repro.kernels import hier_agg as _ha
 from repro.kernels import wkv6 as _wkv
-from repro.telemetry import ktime as _ktime
 
 
 def _by_platform(kernel, *args, **static):
@@ -47,21 +46,10 @@ def hier_agg(bank, weights, *, bn=None):
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "bn"))
-def _segment_agg_jit(bank, weights, segment_ids, num_segments, *,
-                     bn=None):
+def segment_agg(bank, weights, segment_ids, num_segments, *, bn=None):
+    """(N, P) x (N,) weights x (N,) segment ids -> (E, P) f32 means."""
     return _by_platform(_ha.segment_agg, bank, weights, segment_ids,
                         num_segments=num_segments, bn=bn)
-
-
-def segment_agg(bank, weights, segment_ids, num_segments, *, bn=None):
-    """(N, P) x (N,) weights x (N,) segment ids -> (E, P) f32 means.
-
-    Routed through ``repro.telemetry.ktime`` so opt-in wall-clock
-    kernel timing (``kernel_timing``) can observe dispatches; with no
-    registry installed this is a single ``None`` check in front of the
-    unchanged jit call."""
-    return _ktime.call_timed("segment_agg", _segment_agg_jit, bank,
-                             weights, segment_ids, num_segments, bn=bn)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "bn"))
@@ -84,19 +72,10 @@ def segment_agg_sharded(bank, weights, segment_ids, num_segments,
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "bn"))
-def _segment_broadcast_jit(models, segment_ids, *, out_dtype=None,
-                           bn=None):
+def segment_broadcast(models, segment_ids, *, out_dtype=None, bn=None):
+    """(E, P) x (N,) segment ids -> (N, P) bank resync (fused gather)."""
     return _by_platform(_ha.segment_broadcast, models, segment_ids,
                         out_dtype=out_dtype, bn=bn)
-
-
-def segment_broadcast(models, segment_ids, *, out_dtype=None, bn=None):
-    """(E, P) x (N,) segment ids -> (N, P) bank resync (fused gather).
-
-    Same opt-in timing routing as ``segment_agg``."""
-    return _ktime.call_timed("segment_broadcast", _segment_broadcast_jit,
-                             models, segment_ids, out_dtype=out_dtype,
-                             bn=bn)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))

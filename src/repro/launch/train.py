@@ -16,6 +16,12 @@ this is the paper's insight transposed to the TPU interconnect hierarchy.
 ``static`` frequencies compile the loops directly (dry-run / roofline
 path); the ``dynamic`` path takes traced per-edge (γ1, γ2) from the Arena
 agent with masked upper-bound loops (no recompilation between actions).
+
+Device scopes name a round's phases in the profiler trace, with the
+names the Arena round uses (``repro.core.hfl``): ``hfl.local`` (one
+local epoch on every replica, with the masking of inactive ones),
+``hfl.update`` inside it (the SGD update), ``hfl.edge_agg`` and
+``hfl.cloud_agg``. Scopes are metadata: the compiled step is the same.
 """
 from __future__ import annotations
 
@@ -30,6 +36,7 @@ from repro.launch import mesh as mesh_lib
 from repro.models import build_model
 
 
+@jax.named_scope("hfl.update")
 def _sgd(params, grads, lr: float):
     return jax.tree.map(
         lambda p, g: (p.astype(jnp.float32)
@@ -134,6 +141,7 @@ def make_hfl_train_step(cfg, hfl_mesh, *, replicas: Optional[tuple] = None,
             lambda a: a.astype(collective_dtype)
             if jnp.issubdtype(a.dtype, jnp.floating) else a, t))
 
+    @jax.named_scope("hfl.cloud_agg")
     def cloud_agg(params):
         if collective_dtype is None:
             return _cloud_mean(params)
@@ -147,11 +155,13 @@ def make_hfl_train_step(cfg, hfl_mesh, *, replicas: Optional[tuple] = None,
             batch = reshape_batch(batch)
 
             def edge_period(params, _):
+                @jax.named_scope("hfl.local")
                 def local(params, _):
                     return epoch_all(params, batch), None
 
                 params, _ = jax.lax.scan(local, params, None, length=g1)
-                return _edge_mean(params), None
+                with jax.named_scope("hfl.edge_agg"):
+                    return _edge_mean(params), None
 
             params, _ = jax.lax.scan(edge_period, params, None, length=g2)
             return cloud_agg(params)
@@ -164,6 +174,7 @@ def make_hfl_train_step(cfg, hfl_mesh, *, replicas: Optional[tuple] = None,
                 params = carry
                 active2 = t2 < g2e                       # (E,)
 
+                @jax.named_scope("hfl.local")
                 def local(params, t1):
                     new = epoch_all(params, batch)
                     act = (t1 < g1e) & active2
@@ -171,8 +182,9 @@ def make_hfl_train_step(cfg, hfl_mesh, *, replicas: Optional[tuple] = None,
 
                 params2, _ = jax.lax.scan(local, params,
                                           jnp.arange(max_g1))
-                agg = _edge_mean(params2)
-                return _edge_mask(params, agg, active2), None
+                with jax.named_scope("hfl.edge_agg"):
+                    agg = _edge_mean(params2)
+                    return _edge_mask(params, agg, active2), None
 
             params, _ = jax.lax.scan(edge_period, params,
                                      jnp.arange(max_g2))

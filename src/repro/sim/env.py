@@ -17,6 +17,18 @@ One ``HFLEnv`` step = one cloud aggregation round driven by the
 per-edge action (γ1, γ2) — exactly Algorithm 1's inner loop, with the
 synchronous barrier ``t_use = max_j t_edge_j``.
 
+In the profiler trace the environment's host work sits in spans: a
+step is ``hfl.step`` and a reset ``hfl.reset``, and inside them
+``hfl.costs`` (mobility and the hardware cost draws), ``hfl.round``
+(preparing and dispatching the cloud round), ``hfl.acc`` (the accuracy,
+which waits for the round), ``hfl.state`` and, in a reset,
+``hfl.fit_pca``; the reward, the health check and the bookkeeping are
+the step's own. Its device work outside the round (the accuracy and the
+PCA projection) is scoped ``hfl.eval``. ``epoch_counts`` counts the
+device-epochs real-mode rounds compute (every device γmax² a round) and
+those they keep (Σ γ1·γ2 over participating devices), reset rounds
+included.
+
 ``AsyncHFLEnv`` (below) removes that barrier: edges run on their own
 clocks through the event-driven runtime (``repro.runtime``), the cloud
 aggregates a staleness-decayed update buffer, and one env step = one
@@ -31,6 +43,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import hfl, pca, profiling, reward as reward_mod, state as state_mod
 from repro.data import federated, synthetic
@@ -165,9 +178,9 @@ class HFLEnv:
                 # so no round re-ships (or replicates) the full dataset
                 self.fed.x = self.agg_ctx.place_rows(self.fed.x)
                 self.fed.y = self.agg_ctx.place_rows(self.fed.y)
-            self._acc_fn = jax.jit(
+            self._acc_fn = jax.jit(jax.named_scope("hfl.eval")(
                 lambda p, x, y: model_mod.cnn_accuracy(
-                    self._apply_fn, p, {"x": x, "y": y}))
+                    self._apply_fn, p, {"x": x, "y": y})))
         else:
             # analytic mode still needs a (tiny) parameter vector so the
             # PCA state path exercises the real machinery
@@ -180,6 +193,8 @@ class HFLEnv:
              for j in range(cfg.n_edges)], np.float32)
         self.episode = 0
         self._key = jax.random.PRNGKey(cfg.seed)
+        self.epoch_counts = {"computed": 0, "useful": 0}
+        self._round_epochs = (0, 0)
 
     # ------------------------------------------------------------------
     def _next_key(self):
@@ -187,6 +202,10 @@ class HFLEnv:
         return sub
 
     def reset(self) -> np.ndarray:
+        with TraceAnnotation("hfl.reset"):
+            return self._reset()
+
+    def _reset(self) -> np.ndarray:
         cfg = self.cfg
         self.k = 0
         self.t_re = cfg.threshold_time
@@ -225,11 +244,12 @@ class HFLEnv:
         return self._state()
 
     def _fit_pca(self):
-        flat = [pca.flatten_model(self.global_model)]
-        for j in range(self.cfg.n_edges):
-            flat.append(pca.flatten_model(
-                jax.tree.map(lambda a: a[j], self.edge_models)))
-        self.pca_state = pca.fit(jnp.stack(flat), self.cfg.n_pca)
+        # op by op, as it always ran: under jit XLA rounds the Gram
+        # matrix differently and eigh may return a component with the
+        # opposite sign, which would change the agent's state
+        with TraceAnnotation("hfl.fit_pca"):
+            self.pca_state = pca.fit(state_mod.model_matrix(
+                self.global_model, self.edge_models), self.cfg.n_pca)
 
     # ------------------------------------------------------------------
     def _run_round(self, g1: np.ndarray, g2: np.ndarray,
@@ -237,46 +257,58 @@ class HFLEnv:
         """Executes one cloud round; returns (h_edges (M,3), t_use, E)."""
         cfg = self.cfg
         m = cfg.n_edges
-        # --- device mobility ------------------------------------------------
-        if cfg.churn_prob > 0:
-            moved = self.rng.random(cfg.n_devices) < cfg.churn_prob
-            if moved.any():
-                self.profiles.cpu_usage[moved] = self.rng.choice(
-                    [0.1, 0.2, 0.3, 0.4, 0.5], size=int(moved.sum()))
-            if (cfg.recluster_every and cfg.use_profiling
-                    and self.k % cfg.recluster_every == 0 and self.k > 0):
-                self.set_topology(profiling.cluster_devices(
-                    self.profiles, cfg.n_edges, seed=cfg.seed + self.k))
-        # --- hardware costs ------------------------------------------------
-        et = self.profiles.epoch_time(self.rng)          # (N,)
-        ee = self.profiles.epoch_energy(self.rng)        # (N,)
-        ec = self.comm.ec_time(self.rng)                 # (M,)
-        de = self.comm.de_time(self.rng, m)              # (M,)
-        if participate is None:
-            participate = np.ones(cfg.n_devices, bool)
-        t_sgd = np.zeros(m)
-        e_edge = np.zeros(m)
-        for j in range(m):
-            sel = (self.edge_assign == j) & participate
-            if sel.any():
-                t_sgd[j] = et[sel].max()
-                e_edge[j] = (ee[sel] * g1[j] * g2[j]).sum()
-        t_edge = g2 * (g1 * t_sgd + de) + ec
-        t_use = float(t_edge.max())
-        e_tot = float(e_edge.sum())
+        with TraceAnnotation("hfl.costs"):
+            # --- device mobility --------------------------------------------
+            if cfg.churn_prob > 0:
+                moved = self.rng.random(cfg.n_devices) < cfg.churn_prob
+                if moved.any():
+                    self.profiles.cpu_usage[moved] = self.rng.choice(
+                        [0.1, 0.2, 0.3, 0.4, 0.5], size=int(moved.sum()))
+                if (cfg.recluster_every and cfg.use_profiling
+                        and self.k % cfg.recluster_every == 0
+                        and self.k > 0):
+                    self.set_topology(profiling.cluster_devices(
+                        self.profiles, cfg.n_edges, seed=cfg.seed + self.k))
+            # --- hardware costs ---------------------------------------------
+            et = self.profiles.epoch_time(self.rng)          # (N,)
+            ee = self.profiles.epoch_energy(self.rng)        # (N,)
+            ec = self.comm.ec_time(self.rng)                 # (M,)
+            de = self.comm.de_time(self.rng, m)              # (M,)
+            if participate is None:
+                participate = np.ones(cfg.n_devices, bool)
+            t_sgd = np.zeros(m)
+            e_edge = np.zeros(m)
+            for j in range(m):
+                sel = (self.edge_assign == j) & participate
+                if sel.any():
+                    t_sgd[j] = et[sel].max()
+                    e_edge[j] = (ee[sel] * g1[j] * g2[j]).sum()
+            t_edge = g2 * (g1 * t_sgd + de) + ec
+            t_use = float(t_edge.max())
+            e_tot = float(e_edge.sum())
         # --- model update ---------------------------------------------------
         if cfg.mode == "real":
-            part = jnp.asarray(participate)
-            sizes = self.fed.device_sizes() * part.astype(jnp.float32)
-            self.bank, self.global_model, self.edge_models = \
-                self._cloud_round(
-                    self.bank, self.fed.x, self.fed.y, sizes,
-                    self._edge_assign_j,
-                    jnp.asarray(np.minimum(g1, cfg.gamma_max)),
-                    jnp.asarray(np.minimum(g2, cfg.gamma_max)),
-                    self._next_key())
-            acc = float(self._acc_fn(self.global_model, self.fed.test_x,
-                                     self.fed.test_y))
+            g1c = np.minimum(g1, cfg.gamma_max)
+            g2c = np.minimum(g2, cfg.gamma_max)
+            # every device computes gamma_max^2 epochs; the masks keep
+            # gamma1 * gamma2 of them, and aggregation weighs only the
+            # participating devices
+            self._round_epochs = (
+                cfg.n_devices * cfg.gamma_max ** 2,
+                int(np.sum((g1c * g2c)[self.edge_assign][participate])))
+            self.epoch_counts["computed"] += self._round_epochs[0]
+            self.epoch_counts["useful"] += self._round_epochs[1]
+            with TraceAnnotation("hfl.round"):
+                part = jnp.asarray(participate)
+                sizes = self.fed.device_sizes() * part.astype(jnp.float32)
+                self.bank, self.global_model, self.edge_models = \
+                    self._cloud_round(
+                        self.bank, self.fed.x, self.fed.y, sizes,
+                        self._edge_assign_j, jnp.asarray(g1c),
+                        jnp.asarray(g2c), self._next_key())
+            with TraceAnnotation("hfl.acc"):
+                acc = float(self._acc_fn(self.global_model,
+                                         self.fed.test_x, self.fed.test_y))
         else:
             acc = self._analytic_update(g1, g2, participate)
         self.acc = acc
@@ -303,10 +335,11 @@ class HFLEnv:
     # ------------------------------------------------------------------
     def _state(self) -> np.ndarray:
         if self.cfg.mode == "real":
-            return state_mod.build_state(
-                self.pca_state, self.global_model, self.edge_models,
-                self._h_edges, self.k, self.t_re, self.acc,
-                t_threshold=self.cfg.threshold_time)
+            with TraceAnnotation("hfl.state"):
+                return state_mod.build_state(
+                    self.pca_state, self.global_model, self.edge_models,
+                    self._h_edges, self.k, self.t_re, self.acc,
+                    t_threshold=self.cfg.threshold_time)
         # analytic mode: PCA rows replaced by per-edge epoch statistics
         m = self.cfg.n_edges
         s1 = np.zeros((m + 1, self.cfg.n_pca), np.float32)
@@ -323,6 +356,10 @@ class HFLEnv:
         """action: (2M,) raw continuous; projected to γ ∈ [1, γ_max]^2M
         (§3.6 nearest-feasible-solution — with a box feasible set the
         L2-nearest integer point is clip(round(·)))."""
+        with TraceAnnotation("hfl.step"):
+            return self._step(action)
+
+    def _step(self, action: np.ndarray):
         cfg = self.cfg
         m = cfg.n_edges
         a = np.clip(np.round(np.asarray(action)), 1, cfg.gamma_max)
@@ -339,7 +376,9 @@ class HFLEnv:
         self.acc_hist.append(self.acc)
         self.time_hist.append(t_use)
         info = {"acc": self.acc, "energy": e_tot, "t_use": t_use,
-                "t_re": self.t_re, "g1": g1, "g2": g2}
+                "t_re": self.t_re, "g1": g1, "g2": g2,
+                "epochs_computed": self._round_epochs[0],
+                "epochs_useful": self._round_epochs[1]}
         self._observe_health(info)
         return self._state(), float(r), bool(done), info
 
@@ -386,6 +425,11 @@ class HFLEnv:
 
     def step_raw(self, g1: np.ndarray, g2: np.ndarray,
                  participate: Optional[np.ndarray] = None):
+        with TraceAnnotation("hfl.step"):
+            return self._step_raw(g1, g2, participate)
+
+    def _step_raw(self, g1: np.ndarray, g2: np.ndarray,
+                  participate: Optional[np.ndarray] = None):
         acc_old = self.acc
         h_edges, t_use, e_tot = self._run_round(
             np.asarray(g1, np.int64), np.asarray(g2, np.int64), participate)
@@ -397,7 +441,9 @@ class HFLEnv:
         self.acc_hist.append(self.acc)
         self.time_hist.append(t_use)
         info = {"acc": self.acc, "energy": e_tot, "t_use": t_use,
-                "t_re": self.t_re}
+                "t_re": self.t_re,
+                "epochs_computed": self._round_epochs[0],
+                "epochs_useful": self._round_epochs[1]}
         self._observe_health(info)
         return self._state(), float(r), bool(self.t_re < 0), info
 

@@ -10,9 +10,6 @@ observable. This package is that layer:
 * :class:`MetricsRegistry` (``metrics``) — counters / gauges /
   histograms (staleness at flush, survivor coverage, retries, queue
   depth, drops, per-edge upload latency) with per-episode snapshots;
-* :mod:`ktime` — opt-in wall-clock timing of the Pallas
-  ``segment_agg`` / ``segment_broadcast`` launches into the same
-  registry shape;
 * :mod:`ledger` — the persistent run ledger (:class:`RunLedger`):
   append-only JSONL experiment streams recorded by
   ``core.sync.run_scheme`` (DESIGN.md §8);
@@ -25,8 +22,8 @@ telemetry enabled vs disabled reproduces trajectories **bitwise**, on
 single-chip and sharded meshes, faults included. Collectors observe
 the event stream; they never draw RNG, never mutate runtime state,
 never reorder the queue. A disabled :class:`Telemetry` is a zero-cost
-no-op: every hook early-returns, the event queue keeps a ``None``
-observer, and the kernel-timing path is one module-global check.
+no-op: every hook early-returns and the event queue keeps a ``None``
+observer.
 
 Wiring: ``AsyncHFLEnv(cfg, ..., telemetry=Telemetry())`` (or
 ``EnvConfig(telemetry=True)``); the env installs the queue observer,
@@ -36,11 +33,9 @@ hands the buffer/injector their hooks, and plumbs
 """
 from __future__ import annotations
 
-from repro.telemetry import ktime  # noqa: F401
 from repro.telemetry import ledger  # noqa: F401
 from repro.telemetry.health import (  # noqa: F401
     HealthAbort, HealthConfig, HealthEvent, HealthMonitor)
-from repro.telemetry.ktime import kernel_timing  # noqa: F401
 from repro.telemetry.ledger import RunLedger  # noqa: F401
 from repro.telemetry.metrics import MetricsRegistry  # noqa: F401
 from repro.telemetry.recorder import TraceRecorder  # noqa: F401

@@ -10,9 +10,6 @@
   ``info["telemetry"]``;
 * exported Chrome-trace JSON validates against the Trace Event Format
   schema (``chrome://tracing`` / Perfetto compatible);
-* the opt-in kernel-timing hooks (``repro.telemetry.ktime``) record
-  dispatch timings without changing kernel outputs, skip jit-traced
-  calls, and nest/restore cleanly;
 * telemetry state rides checkpoints: save/restore mid-episode and the
   finished run emits the same trace as an uninterrupted one.
 """
@@ -22,15 +19,10 @@ import os
 import numpy as np
 import pytest
 
-import jax
-import jax.numpy as jnp
-
 from repro.checkpoint import store
-from repro.kernels import ops
 from repro.runtime import AsyncConfig, ChurnEvent, FaultSpec, Outage
 from repro.sim.env import AsyncHFLEnv, EnvConfig
-from repro.telemetry import (MetricsRegistry, Telemetry, TraceRecorder,
-                             kernel_timing, ktime)
+from repro.telemetry import MetricsRegistry, Telemetry, TraceRecorder
 
 import _subproc
 
@@ -283,72 +275,6 @@ def test_jsonl_export_streams_every_event(tmp_path):
     with open(path) as f:
         lines = [json.loads(line) for line in f]
     assert lines == env.telemetry.recorder.events
-
-
-# ---------------------------------------------------------------------------
-# opt-in kernel timing (repro.telemetry.ktime)
-# ---------------------------------------------------------------------------
-
-def _kernel_inputs():
-    rng = np.random.default_rng(3)
-    bank = jnp.asarray(rng.normal(size=(8, 37)), jnp.float32)
-    w = jnp.asarray(rng.uniform(0.5, 1.5, size=(8,)), jnp.float32)
-    seg = jnp.asarray(np.repeat(np.arange(4), 2), jnp.int32)
-    return bank, w, seg
-
-
-def test_kernel_timing_records_without_changing_outputs():
-    bank, w, seg = _kernel_inputs()
-    base_agg = ops.segment_agg(bank, w, seg, 4)
-    base_bc = ops.segment_broadcast(base_agg, seg)
-    reg = MetricsRegistry()
-    with kernel_timing(reg):
-        timed_agg = ops.segment_agg(bank, w, seg, 4)
-        timed_bc = ops.segment_broadcast(timed_agg, seg)
-    np.testing.assert_array_equal(np.asarray(timed_agg),
-                                  np.asarray(base_agg))
-    np.testing.assert_array_equal(np.asarray(timed_bc),
-                                  np.asarray(base_bc))
-    assert reg.counters["kernel/segment_agg_calls"] == 1
-    assert reg.counters["kernel/segment_broadcast_calls"] == 1
-    assert len(reg.hists["kernel/segment_agg_us"]) == 1
-    assert reg.hists["kernel/segment_agg_us"][0] > 0
-    # leaving the context deactivates the sink
-    assert ktime.active_registry() is None
-    ops.segment_agg(bank, w, seg, 4)
-    assert reg.counters["kernel/segment_agg_calls"] == 1
-
-
-def test_kernel_timing_skips_jit_traced_calls():
-    """Launches traced inside an outer jit (the compiled round bodies)
-    see abstract values — the hook must fall through, not time them."""
-    bank, w, seg = _kernel_inputs()
-
-    @jax.jit
-    def round_body(b, ww):
-        return ops.segment_agg(b, ww, seg, 4)
-
-    reg = MetricsRegistry()
-    with kernel_timing(reg):
-        out = round_body(bank, w)
-    np.testing.assert_array_equal(
-        np.asarray(out), np.asarray(ops.segment_agg(bank, w, seg, 4)))
-    assert "kernel/segment_agg_calls" not in reg.counters
-
-
-def test_kernel_timing_nests_and_restores():
-    bank, w, seg = _kernel_inputs()
-    outer, inner = MetricsRegistry(), MetricsRegistry()
-    with kernel_timing(outer):
-        ops.segment_agg(bank, w, seg, 4)
-        with kernel_timing(inner):
-            assert ktime.active_registry() is inner
-            ops.segment_agg(bank, w, seg, 4)
-        assert ktime.active_registry() is outer
-        ops.segment_agg(bank, w, seg, 4)
-    assert ktime.active_registry() is None
-    assert outer.counters["kernel/segment_agg_calls"] == 2
-    assert inner.counters["kernel/segment_agg_calls"] == 1
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ imports this file. The persistent compilation cache is off around these
 compiles (an entry compiled for an absent chip cannot be read back).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,3 +113,20 @@ def test_ops_compile_on_tpu(one_chip, name):
     text = _compiled_text(fn, one_chip, *shapes)
     assert "tpu_custom_call" in text
     assert "conditional" not in text
+
+
+@pytest.mark.parametrize("name", ["segment_agg", "segment_broadcast"])
+def test_aggregation_kernels_keep_their_names(one_chip, name):
+    """A TPU trace names an operation after its HLO instruction: the
+    Mosaic call of each aggregation kernel is ``<name>.N``, whatever
+    the code around it is called."""
+    n, p, e = AGG_SHAPES[1]
+    f32, i32 = jnp.float32, jnp.int32
+    fn, shapes = {
+        "segment_agg": (lambda b, w, s: ops.segment_agg(b, w, s, e),
+                        [((n, p), f32), ((n,), f32), ((n,), i32)]),
+        "segment_broadcast": (ops.segment_broadcast,
+                              [((e, p), f32), ((n,), i32)]),
+    }[name]
+    text = _compiled_text(fn, one_chip, *shapes)
+    assert re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(", text), name
