@@ -15,8 +15,13 @@ directly in the bank's storage dtype. The old per-leaf tree path lives
 on as the parity oracle ``repro.kernels.ref.weighted_aggregate_ref``.
 
 Per-edge frequencies (γ1_j, γ2_j) are traced values — one compiled
-``hfl_cloud_round`` serves every action the agent picks, via masked
-upper-bound loops (``max_g1``/``max_g2`` static). ``make_cloud_round``
+``hfl_cloud_round`` serves every action the agent picks. Its loops take
+their trip counts from the action: the γ2 loop runs max_j γ2_j steps and
+each step's epoch loop max γ1 over the edges still active in it; a
+device whose own γ1 is smaller is masked. The iterations left out are
+the tail of each loop, so the key chain and the result are those of
+loops run to the ceilings ``max_g1``/``max_g2`` (``round_epochs``
+counts what a round runs). ``make_cloud_round``
 and ``make_fedavg_round`` return jit-compiled rounds that donate the
 incoming bank buffer, so steady-state training re-uses the bank
 allocation instead of copying it every round.
@@ -64,6 +69,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
@@ -421,8 +427,10 @@ def make_local_trainer(loss_fn: Callable, lr: float, batch_size: int):
     loss_fn(params, batch) -> scalar. One 'epoch' = one pass over the
     device's local shard in shuffled minibatches (the paper's unit: γ1
     epochs of local SGD between edge aggregations).
-    gamma1_dev: (N,) traced per-device epoch counts; epochs beyond a
-    device's γ1 are masked no-ops (static bound ``max_g1``).
+    gamma1_dev: (N,) traced per-device epoch counts. The loop runs
+    max_i γ1_i epochs (at most ``max_g1``, the action's ceiling), and
+    a device whose γ1 is smaller keeps its model through the epochs
+    past it.
 
     The same function serves sharded rounds: under GSPMD with bank/x/y
     row-sharded, the vmapped epoch partitions on the device axis and the
@@ -449,7 +457,7 @@ def make_local_trainer(loss_fn: Callable, lr: float, batch_size: int):
         n, n_local = x.shape[0], x.shape[1]
 
         @jax.named_scope("hfl.local")
-        def one_epoch(carry, e):
+        def one_epoch(e, carry):
             bank, key = carry
             key, sub = jax.random.split(key)
             perms = jax.vmap(
@@ -463,10 +471,10 @@ def make_local_trainer(loss_fn: Callable, lr: float, batch_size: int):
                 return jnp.where(am, b, a)
 
             bank = jax.tree.map(mask, bank, new)
-            return (bank, key), None
+            return bank, key
 
-        (bank, _), _ = jax.lax.scan(one_epoch, (bank, key),
-                                    jnp.arange(max_g1))
+        n_epochs = jnp.minimum(jnp.max(gamma1_dev), max_g1)
+        bank, _ = jax.lax.fori_loop(0, n_epochs, one_epoch, (bank, key))
         return bank
 
     return local_train
@@ -510,6 +518,21 @@ def _jit_round(fn, mesh, n_row_args: int, donate: tuple):
     return call
 
 
+def round_epochs(g1, g2, edge_assign, max_g1: int, max_g2: int) -> int:
+    """Device-epochs one ``make_cloud_round`` round computes for the
+    action (g1 (M,), g2 (M,)): every device runs each epoch of the
+    loops, N × Σ_{t2 < max_j γ2_j} max{γ1_i : γ2_i > t2} over its
+    devices i, with the round's ceilings applied as its loops apply
+    them. The masked epochs are counted; the useful ones are Σ_i γ1_i γ2_i.
+    """
+    ea = np.asarray(edge_assign)
+    g1_dev = np.asarray(g1)[ea]
+    g2_dev = np.asarray(g2)[ea]
+    steps = min(int(np.max(g2)), max_g2)
+    return ea.size * sum(min(int(np.max(np.where(t2 < g2_dev, g1_dev, 0))),
+                             max_g1) for t2 in range(steps))
+
+
 def make_cloud_round(loss_fn: Callable, lr: float, batch_size: int,
                      n_edges: int, max_g1: int, max_g2: int,
                      ctx: Optional[AggContext] = None, mesh=None):
@@ -520,13 +543,16 @@ def make_cloud_round(loss_fn: Callable, lr: float, batch_size: int,
 
     Composition per Eq. 5: for t2 < γ2_j, devices of edge j run γ1_j local
     epochs then edge-aggregate; edges past their γ2_j freeze; finally the
-    cloud aggregates the edge models and broadcasts.
+    cloud aggregates the edge models and broadcasts. The t2 loop runs
+    max_j γ2_j steps and step t2's epoch loop max{γ1_j : γ2_j > t2}
+    epochs (each at most its ceiling ``max_g2``/``max_g1``), so a round
+    computes ``round_epochs`` device-epochs.
 
     The t2 loop carries the edge models as a flat ``(E, P)`` f32 matrix:
     each step flattens the trained bank once, edge-aggregates in one
     ``segment_agg`` launch, masks frozen edges with a single 2-D
     ``where``, and resyncs the bank through ``segment_broadcast`` — no
-    per-leaf tree traffic inside the scan.
+    per-leaf tree traffic inside the loop.
 
     With a sharded ``ctx`` the same body compiles under GSPMD with bank
     rows, data shards, sizes, and edge assignment partitioned over the
@@ -562,7 +588,7 @@ def make_cloud_round(loss_fn: Callable, lr: float, batch_size: int,
             resync = lambda em: _smap_segment_broadcast(mesh, spec.dtype)(
                 em, edge_assign)
 
-        def t2_step(carry, t2):
+        def t2_step(t2, carry):
             bank, edge_mat, key = carry
             key, sub = jax.random.split(key)
             active_dev = t2 < g2_dev
@@ -575,12 +601,15 @@ def make_cloud_round(loss_fn: Callable, lr: float, batch_size: int,
                 # devices resume from their edge's current model (each
                 # shard gathers only its own rows under the mesh path)
                 bank = spec.unflatten(resync(edge_mat))
-            return (bank, edge_mat, key), None
+            return bank, edge_mat, key
 
         with jax.named_scope("hfl.edge_agg"):
             edge_mat0 = agg(spec.flatten(bank))
-        (bank, edge_mat, _), _ = jax.lax.scan(
-            t2_step, (bank, edge_mat0, key), jnp.arange(max_g2))
+        # from the replicated (M,) action: under a mesh the trip count
+        # needs no cross-shard reduction
+        n_steps = jnp.minimum(jnp.max(g2), max_g2)
+        bank, edge_mat, _ = jax.lax.fori_loop(
+            0, n_steps, t2_step, (bank, edge_mat0, key))
         with jax.named_scope("hfl.cloud_agg"):
             edge_sizes = jax.ops.segment_sum(sizes, edge_assign, n_edges)
             glob = agg1(edge_mat, edge_sizes)[0]
@@ -614,13 +643,15 @@ def make_edge_round(loss_fn: Callable, lr: float, batch_size: int,
     carried untouched (the bank is a shared scratch buffer across
     interleaved edge rounds).
 
-    Bitwise contract with ``make_cloud_round``: the loop structure, key
+    Bitwise contract with ``make_cloud_round``: the loop body, key
     chain, and kernel launches are the *same program* restricted to one
     edge — masked weights zero the other edges out of the one-hot
-    matmuls, so with every edge starting from the same ``global_vec``
-    and the same ``key``, edge ``j``'s returned update equals row ``j``
-    of the synchronous round's edge matrix bit for bit (the async-parity
-    test in tests/test_async_runtime.py pins this).
+    matmuls, and the loops run this edge's γ2 steps of γ1 epochs, the
+    head of the synchronous round's loops — so with every edge starting
+    from the same ``global_vec`` and the same ``key``, edge ``j``'s
+    returned update equals row ``j`` of the synchronous round's edge
+    matrix bit for bit (the async-parity test in
+    tests/test_async_runtime.py pins this).
 
     With a sharded ``ctx`` the round compiles under GSPMD exactly like
     ``make_cloud_round``: bank/data/sizes/assignment row-sharded,
@@ -670,7 +701,7 @@ def make_edge_round(loss_fn: Callable, lr: float, batch_size: int,
         row_mask = row_active.reshape(-1, 1)
         edge_1h = (jnp.arange(n_edges) == edge_id).reshape(-1, 1)
 
-        def t2_step(carry, t2):
+        def t2_step(t2, carry):
             bank, edge_mat, key = carry
             key, sub = jax.random.split(key)
             active_dev = t2 < g2_dev
@@ -685,12 +716,12 @@ def make_edge_round(loss_fn: Callable, lr: float, batch_size: int,
                 mat = jnp.where(row_mask, resync(edge_mat),
                                 spec.flatten(bank))
                 bank = spec.unflatten(mat)
-            return (bank, edge_mat, key), None
+            return bank, edge_mat, key
 
         with jax.named_scope("hfl.edge_agg"):
             edge_mat0 = agg(spec.flatten(bank))
-        (bank, edge_mat, _), _ = jax.lax.scan(
-            t2_step, (bank, edge_mat0, key), jnp.arange(max_g2))
+        bank, edge_mat, _ = jax.lax.fori_loop(
+            0, jnp.minimum(g2, max_g2), t2_step, (bank, edge_mat0, key))
         edge_vec = jnp.take(edge_mat, edge_id, axis=0)
         return bank, edge_vec
 

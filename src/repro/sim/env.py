@@ -25,9 +25,9 @@ which waits for the round), ``hfl.state`` and, in a reset,
 ``hfl.fit_pca``; the reward, the health check and the bookkeeping are
 the step's own. Its device work outside the round (the accuracy and the
 PCA projection) is scoped ``hfl.eval``. ``epoch_counts`` counts the
-device-epochs real-mode rounds compute (every device γmax² a round) and
-those they keep (Σ γ1·γ2 over participating devices), reset rounds
-included.
+device-epochs real-mode rounds compute (``hfl.round_epochs``: every
+device runs each epoch of the round's loops) and those they keep
+(Σ γ1·γ2 over participating devices), reset rounds included.
 
 ``AsyncHFLEnv`` (below) removes that barrier: edges run on their own
 clocks through the event-driven runtime (``repro.runtime``), the cloud
@@ -290,11 +290,12 @@ class HFLEnv:
         if cfg.mode == "real":
             g1c = np.minimum(g1, cfg.gamma_max)
             g2c = np.minimum(g2, cfg.gamma_max)
-            # every device computes gamma_max^2 epochs; the masks keep
-            # gamma1 * gamma2 of them, and aggregation weighs only the
-            # participating devices
+            # every device runs each epoch of the round's loops; the
+            # masks keep gamma1 * gamma2 of them, and aggregation weighs
+            # only the participating devices
             self._round_epochs = (
-                cfg.n_devices * cfg.gamma_max ** 2,
+                hfl.round_epochs(g1c, g2c, self.edge_assign,
+                                 cfg.gamma_max, cfg.gamma_max),
                 int(np.sum((g1c * g2c)[self.edge_assign][participate])))
             self.epoch_counts["computed"] += self._round_epochs[0]
             self.epoch_counts["useful"] += self._round_epochs[1]

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import _fixed_rounds
 from _hypothesis_compat import given, settings, st
 
 from repro.core import convergence, hfl, pca, profiling
@@ -92,6 +93,48 @@ def test_cloud_round_synchronizes_bank():
         np.testing.assert_allclose(w[i], w[0], atol=1e-6)
     # training moved the model
     assert np.abs(np.asarray(glob["w"]) - np.asarray(p0["w"])).max() > 1e-4
+
+
+@pytest.fixture(scope="module")
+def one_chip_rounds():
+    """The rounds under test and the fixed-loop oracle, built once so
+    every action reuses their compiled programs."""
+    c = _fixed_rounds
+    m, g = 3, c.MAX_G
+    new = {"cloud": hfl.make_cloud_round(c.tiny_loss, 0.1, 4, m, g, g),
+           "edge": hfl.make_edge_round(c.tiny_loss, 0.1, 4, m, g, g),
+           "fedavg": hfl.make_fedavg_round(c.tiny_loss, 0.1, 4, g)}
+    old = dict(zip(("cloud", "edge", "fedavg"),
+                   c.fixed_rounds(c.tiny_loss, 0.1, 4, m, g, g)))
+    return new, old
+
+
+@pytest.mark.parametrize("action", list(_fixed_rounds.ACTIONS))
+@pytest.mark.parametrize("kind", ["cloud", "edge", "fedavg"])
+def test_rounds_skip_only_unneeded_epochs_bitwise(one_chip_rounds, kind,
+                                                  action):
+    """The rounds run only the epochs some device still needs, and give
+    the fixed max_g1 x max_g2 masked loops' result bit for bit."""
+    new, old = one_chip_rounds
+    case = _fixed_rounds.tiny_case(action)
+    key = jax.random.PRNGKey(5)
+    got = _fixed_rounds.run_case(new, kind, case, key)
+    want = _fixed_rounds.run_case(old, kind, case, key)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+
+
+def test_round_epochs_counts_the_loops():
+    """N x sum over t2 < max gamma2 of the largest gamma1 still active;
+    an edge with no device adds nothing, the ceilings cap both loops."""
+    ea = np.array([0, 1, 0, 1])
+    assert hfl.round_epochs([2, 2], [2, 2], ea, 3, 3) == 4 * 4
+    assert hfl.round_epochs([1, 3], [2, 3], ea, 3, 3) == 4 * 9
+    assert hfl.round_epochs([3, 1], [1, 3], ea, 3, 3) == 4 * (3 + 1 + 1)
+    assert hfl.round_epochs([2, 2, 5], [1, 1, 4], ea, 8, 8) == 4 * 2
+    assert hfl.round_epochs([5, 1], [5, 1], ea, 3, 2) == 4 * 3 * 2
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,7 @@ from repro.core import flatbank, hfl
 from repro.kernels import ops, ref
 from repro.launch import mesh as mesh_lib
 
+import _fixed_rounds
 import _subproc
 
 NDEV = jax.device_count()
@@ -403,6 +404,42 @@ def test_round_rejects_indivisible_rows():
     with pytest.raises(ValueError):
         round_(bank, x, y, sizes, jnp.zeros((10,), jnp.int32),
                jnp.ones((2,)), jnp.ones((2,)), jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def two_shard_rounds():
+    """The rounds under test and the fixed-loop oracle on a forced-host
+    2-shard mesh, built once so every action reuses their programs."""
+    fr = _fixed_rounds
+    ctx = hfl.AggContext.for_mesh(mesh_lib.make_bank_mesh(2, 1))
+    m, g = 3, fr.MAX_G
+    new = {"cloud": hfl.make_cloud_round(fr.tiny_loss, 0.1, 4, m, g, g,
+                                         ctx=ctx),
+           "edge": hfl.make_edge_round(fr.tiny_loss, 0.1, 4, m, g, g,
+                                       ctx=ctx),
+           "fedavg": hfl.make_fedavg_round(fr.tiny_loss, 0.1, 4, g,
+                                           ctx=ctx)}
+    old = dict(zip(("cloud", "edge", "fedavg"),
+                   fr.fixed_rounds(fr.tiny_loss, 0.1, 4, m, g, g, ctx=ctx)))
+    return new, old
+
+
+@needs_mesh
+@pytest.mark.parametrize("action", list(_fixed_rounds.ACTIONS))
+@pytest.mark.parametrize("kind", ["cloud", "edge", "fedavg"])
+def test_sharded_rounds_skip_only_unneeded_epochs_bitwise(
+        two_shard_rounds, kind, action):
+    """Under a mesh the loops' trip counts come from the action too
+    (the epoch bound a cross-shard max of the row-sharded gamma1), and
+    the rounds give the fixed masked loops' result bit for bit."""
+    new, old = two_shard_rounds
+    case = _fixed_rounds.tiny_case(action)
+    key = jax.random.PRNGKey(6)
+    got = _fixed_rounds.run_case(new, kind, case, key)
+    want = _fixed_rounds.run_case(old, kind, case, key)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
 
 
 # ---------------------------------------------------------------------------

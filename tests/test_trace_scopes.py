@@ -99,23 +99,54 @@ def test_environment_eval_is_scoped():
 
 
 def test_epoch_counts_and_info():
-    """Every device computes gamma_max^2 epochs a round; the useful ones
-    are gamma1 * gamma2 of its edge, on participating devices only; the
-    reset round (gamma 2, 2) counts too."""
+    """Every device runs each epoch of the round's loops, N x the sum
+    over t2 < max gamma2 of the largest gamma1 still active; the useful
+    ones are gamma1 * gamma2 of its edge, on participating devices only;
+    the reset round (gamma 2, 2) counts too."""
     env = _tiny_env()
     env.reset()
-    n, g2max = 4, 3 ** 2
-    assert env.epoch_counts == {"computed": n * g2max, "useful": n * 4}
+    n = 4
+    assert env.epoch_counts == {"computed": n * 4, "useful": n * 4}
     _, _, _, info = env.step(np.array([1, 3, 2, 3]))   # g1 (1, 3), g2 (2, 3)
-    # devices 0, 2 on edge 0 (1 * 2), devices 1, 3 on edge 1 (3 * 3)
+    # devices 0, 2 on edge 0 (1 * 2), devices 1, 3 on edge 1 (3 * 3);
+    # all three steps run edge 1's 3 epochs
     assert (info["epochs_computed"], info["epochs_useful"]) == (
-        n * g2max, 2 * 2 + 2 * 9)
+        n * 9, 2 * 2 + 2 * 9)
     _, _, _, info = env.step_raw(np.array([2, 2]), np.array([1, 1]),
                                  participate=np.array([1, 0, 0, 1], bool))
-    assert (info["epochs_computed"], info["epochs_useful"]) == (
-        n * g2max, 2 * 2)
-    assert env.epoch_counts == {"computed": 3 * n * g2max,
+    assert (info["epochs_computed"], info["epochs_useful"]) == (n * 2, 2 * 2)
+    assert env.epoch_counts == {"computed": n * (4 + 9 + 2),
                                 "useful": n * 4 + 22 + 4}
+
+
+@pytest.mark.parametrize("g1,g2", [
+    ([1, 1, 1], [1, 1, 1]),
+    ([3, 1, 2], [1, 3, 2]),
+    ([2, 3, 1], [3, 3, 3]),
+])
+def test_compiled_round_runs_the_counted_epochs(g1, g2):
+    """The compiled cloud round evaluates the loss once per device and
+    minibatch of each epoch ``hfl.round_epochs`` counts: the loops stop
+    where the action's epochs end."""
+    calls = []
+
+    def loss(p, batch):
+        # one value per device and minibatch, whether vmap calls back
+        # once per device or once for all of them
+        jax.debug.callback(lambda v: calls.append(np.size(v)),
+                           batch["x"][0, 0])
+        return _linear_loss(p, batch)
+
+    n, m, max_g = 6, 3, 3
+    bank, x, y, sizes, seg = _round_args(n, m)
+    rnd = hfl.make_cloud_round(loss, 0.1, 4, m, max_g, max_g)
+    out = rnd(bank, x, y, sizes, seg, jnp.array(g1), jnp.array(g2),
+              jax.random.PRNGKey(0))
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    minibatches = x.shape[1] // 4
+    assert sum(calls) == minibatches * hfl.round_epochs(
+        g1, g2, np.asarray(seg), max_g, max_g)
 
 
 def test_host_spans_land_in_the_profiler_trace(tmp_path):
