@@ -95,6 +95,7 @@ class ArenaCell:
         env, n = self.env, len(self.actions)
         steps = samples = resets = 0
         self.bad = 0
+        epochs0 = env.epoch_counts["computed"]
         t0 = time.perf_counter()
         ends = []
         with jax.default_device(self.devices[0]):
@@ -117,8 +118,9 @@ class ArenaCell:
                 if ends[-1] - t0 >= seconds:
                     break
         elapsed = ends[-1] - t0
-        # every round computes gamma_max^2 epochs on every device
-        computed = (steps * self.c["n_devices"] * self.c["gamma_max"] ** 2
+        # the device-epochs the rounds ran: each step's
+        # info["epochs_computed"], and the reset rounds'
+        computed = ((env.epoch_counts["computed"] - epochs0)
                     * (self.c["n_local"] // self.c["batch_size"])
                     * self.c["batch_size"])
         return {"rounds": steps, "elapsed_s": elapsed, "work": steps,

@@ -8,7 +8,15 @@ parameters to the window, which runs whole rounds until the time is up,
 keeping about ``AHEAD_S`` seconds of rounds dispatched ahead of the one
 it waits for, so that a stall of the host does not idle the chip.
 After the window the program's state is freed and the plain reference
-(``ref_qwen3``) follows the same first rounds from the same seed.
+follows the same first rounds from the same seed: the model of the
+module the configuration names under ``"reference"``
+(``spec.load_reference``) inside ``ref_hfl``'s round.
+
+A configuration file sets the program's ``ArchConfig`` through
+``ARCH_KEYS`` and then through its own ``"program_keys"``, which map
+more of its keys to fields (a dotted name reaches a nested one, as
+``"n_routed_experts": "moe.n_experts"``), so one value in the file feeds
+both the program and the reference.
 """
 from __future__ import annotations
 
@@ -22,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 
 import compare
-import ref_qwen3
+import ref_hfl
+import spec
 import traffic as traffic_gen
 
 # configuration-file keys -> the program's ArchConfig fields
@@ -36,14 +45,29 @@ ARCH_KEYS = {
 }
 
 
+def _set_field(obj, path: list, value, full: str):
+    """``obj`` with the field at ``path`` (nested dataclasses) set."""
+    names = ({f.name for f in dataclasses.fields(obj)}
+             if dataclasses.is_dataclass(obj) else set())
+    if path[0] not in names:
+        raise KeyError(f"program_keys: {full!r} names no field of "
+                       f"{type(obj).__name__}")
+    if len(path) > 1:
+        value = _set_field(getattr(obj, path[0]), path[1:], value, full)
+    return dataclasses.replace(obj, **{path[0]: value})
+
+
 def arch_config(c: dict):
     """The program's ArchConfig for configuration file ``c``: the
     program's entry for ``c["program_arch"]`` with every size set from
-    the file."""
+    the file, by ``ARCH_KEYS`` and then by ``c["program_keys"]``."""
     from repro.configs import get_config
-    base = get_config(c["program_arch"])
-    return dataclasses.replace(
-        base, **{f: c[k] for k, f in ARCH_KEYS.items() if k in c})
+    cfg = dataclasses.replace(
+        get_config(c["program_arch"]),
+        **{f: c[k] for k, f in ARCH_KEYS.items() if k in c})
+    for key, field in c.get("program_keys", {}).items():
+        cfg = _set_field(cfg, field.split("."), c[key], field)
+    return cfg
 
 
 # seconds of rounds the window keeps dispatched ahead of the one it
@@ -97,6 +121,8 @@ class LLMCell:
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch import mesh as mesh_lib
         from repro.launch import train
+        # a missing or unknown reference fails here, not after the window
+        spec.load_reference(self.c.get("reference"))
         t = self.t
         make = self._make_trainer or train.make_trainer
         self.step, params, mesh = make(
@@ -205,11 +231,12 @@ def reference_norms(c: dict, t: dict, init_seed: int, host_batches: list,
                     dtype=jnp.float32, fault: str = "") -> dict:
     """The reference's per-leaf change norms after round 1 and after the
     last checked round, on the run's first batches."""
+    ref = spec.load_reference(c.get("reference"))
     with jax.default_matmul_precision("highest"):
-        p0 = jax.jit(lambda k: ref_qwen3.init(k, c))(
+        p0 = jax.jit(lambda k: ref.init(k, c))(
             jax.random.PRNGKey(init_seed))
         p0 = jax.tree.map(lambda a: a.astype(dtype), p0)
-        sgd = ref_qwen3.make_sgd_step(c, t["lr"], dtype)
+        sgd = ref_hfl.make_sgd_step(ref.loss, c, t["lr"], dtype)
         model = jax.tree.map(jnp.copy, p0)
         base = compare.split_layers(compare.flat_leaves(p0))
         out = {}
@@ -230,13 +257,13 @@ def reference_norms(c: dict, t: dict, init_seed: int, host_batches: list,
             if fault == "no_aggregation":
                 # replica (0, 0, 0) alone: its rows, no edge or cloud mean
                 rows = tuple(a[:spr // (mb // mb_k)] for a in rows)
-                model = ref_qwen3.hfl_round(model, rows, (1, 1, 1),
-                                            t["gamma1"], t["gamma2"], mb_k,
-                                            sgd, dtype)
+                model = ref_hfl.hfl_round(model, rows, (1, 1, 1),
+                                          t["gamma1"], t["gamma2"], mb_k,
+                                          sgd, dtype)
             else:
-                model = ref_qwen3.hfl_round(model, rows, replicas,
-                                            t["gamma1"], t["gamma2"], mb_k,
-                                            sgd, dtype)
+                model = ref_hfl.hfl_round(model, rows, replicas,
+                                          t["gamma1"], t["gamma2"], mb_k,
+                                          sgd, dtype)
             if k in (1, t["check_steps"]):
                 now = compare.split_layers(compare.flat_leaves(model))
                 out[k] = {n: float(jnp.sqrt(jnp.sum(jnp.square(

@@ -7,8 +7,12 @@ per-layer metric sits in a file of its own under this directory:
     traffic/<traffic>.json       the parameters of one traffic mix
     limits/<workload>.json       the limits of the cell's correctness check
     metrics/<metric>.py          the reader of one per-layer metric
+    <reference>.py               the plain reference a configuration names
 
 so a cell or a metric is added with new files and new entries alone.
+An LLM configuration names its reference module under ``"reference"``;
+the module gives ``init(key, c)``, ``loss(params, tokens, labels, c,
+dtype)`` and ``train_flops_per_token(c, seq_len)``.
 """
 from __future__ import annotations
 
@@ -23,6 +27,8 @@ ROOT = os.path.dirname(os.path.dirname(HERE))
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+# a module's path under this directory, without ".py": ref_x, tests/ref_y
+REFERENCE_RE = re.compile(r"[A-Za-z_][A-Za-z0-9_]*(/[A-Za-z_][A-Za-z0-9_]*)*")
 
 
 def load_benchmark(root: str = ROOT) -> dict:
@@ -45,6 +51,24 @@ def load_reader(metric: str, here: str = HERE):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def load_reference(name):
+    """The reference module ``<name>.py`` under this directory that a
+    configuration names under ``"reference"``. ValueError for a name
+    that is no module's path (None, where the configuration names
+    none), FileNotFoundError for one with no file; both say the name."""
+    if not isinstance(name, str) or not REFERENCE_RE.fullmatch(name):
+        raise ValueError(f"reference {name!r}: not the path of a module "
+                         f"under {HERE}")
+    path = os.path.join(HERE, name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"reference {name!r}: no file {path}")
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_ref_" + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @dataclasses.dataclass

@@ -25,11 +25,12 @@ def load(path: str) -> dict:
 
 def tiny_cell(workload: str, **traffic_over) -> spec.Cell:
     """The cell ``workload`` of BENCHMARK.json with its configuration
-    swapped for the tiny one of the same kind, the cell's own traffic
-    (cut by ``traffic_over``) and the cell's own limits."""
-    cell = spec.resolve(spec.load_benchmark(), workload)
-    kind = cell.traffic["kind"]
-    tiny = {"llm_train": "tiny-qwen3.json", "arena_env": "tiny-arena.json"}
-    cell.config = load(os.path.join(TESTS, tiny[kind]))
+    swapped for that configuration's tiny stand-in,
+    ``tests/tiny-<config>.json``, the cell's own traffic (cut by
+    ``traffic_over``) and the cell's own limits."""
+    bench = spec.load_benchmark()
+    cell = spec.resolve(bench, workload)
+    config = {w["name"]: w["config"] for w in bench["workloads"]}[workload]
+    cell.config = load(os.path.join(TESTS, f"tiny-{config}.json"))
     cell.traffic = dict(cell.traffic, **traffic_over)
     return cell

@@ -107,3 +107,30 @@ def test_reference_builds_the_programs_weights():
     for k in mine:
         np.testing.assert_allclose(np.asarray(mine[k]), np.asarray(prog[k]),
                                    rtol=1e-6, atol=1e-7, err_msg=k)
+
+
+@pytest.mark.parametrize("gamma_range", [[1, 1], [1, 2]])
+def test_useful_share_is_over_the_epochs_the_rounds_ran(gamma_range):
+    """The window's useful share divides the samples of the epochs the
+    actions ask for by those of the epochs the rounds ran
+    (``hfl.round_epochs`` of each action), not by gamma_max^2 epochs a
+    round: where every gamma is 1, every epoch run is useful."""
+    from repro.core import hfl
+    cell = _chipbench.tiny_cell("mnist-varfreq", gamma_range=gamma_range)
+    d = drive_arena.ArenaCell(cell, SEED, jax.devices())
+    d.setup()
+    start = d.steps_done
+    counts = d.window(0.5)
+    assert counts["resets"] == 0
+    gmax = cell.config["gamma_max"]
+    ran = sum(hfl.round_epochs(*d._gammas(d.actions[(start + i)
+                                                    % len(d.actions)]),
+                               d.env.edge_assign, gmax, gmax)
+              for i in range(counts["rounds"]))
+    per_epoch = (cell.config["n_local"] // cell.config["batch_size"]
+                 * cell.config["batch_size"])
+    assert counts["useful_share"] == pytest.approx(
+        counts["useful_samples"] / (ran * per_epoch), rel=1e-12)
+    assert 0 < counts["useful_share"] <= 1
+    if gamma_range == [1, 1]:
+        assert counts["useful_share"] == 1.0

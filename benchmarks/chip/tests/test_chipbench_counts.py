@@ -1,7 +1,9 @@
 """The yardstick's counts against hand counts, and the peaks table."""
+import os
+
 import pytest
 
-import _chipbench  # noqa: F401  (puts the harness on the path)
+import _chipbench
 import flops
 import peaks
 
@@ -38,3 +40,17 @@ def test_peaks_v5e_and_unknown_kind():
     assert p["hbm_bytes"] == 16e9 and p["ici_bits_per_s"] == 1600e9
     with pytest.raises(KeyError):
         peaks.peaks("cpu")
+
+
+def test_mfu_llm_reads_the_qwen3_cut_count():
+    """``mfu.llm`` on the ``qwen3-1.7b-cut`` configuration file: the dense
+    decoder's count of 240,222,208 matmul parameters and causal
+    attention, at 1024 positions, over the v5e's bf16 peak."""
+    import spec
+    c = _chipbench.load(os.path.join(_chipbench.HERE, "configs",
+                                     "qwen3-1.7b-cut.json"))
+    ctx = {"rates": {"train_tokens_per_s": 31012.0}, "config": c,
+           "traffic": {"seq_len": 1024}, "peaks": peaks.peaks("TPU v5 lite")}
+    per_token = 6 * 240_222_208 + 3 * 4 * 2 * 2 * 16 * 128 * 1025 / 2
+    assert spec.load_reader("mfu.llm")(ctx) == pytest.approx(
+        100.0 * per_token * 31012.0 / 197e12, rel=1e-12)
